@@ -1,16 +1,5 @@
 """CAMformer reproduction package.
 
-Process-wide jax configuration lives here so every entry point (tests,
-launchers, benchmarks) agrees:
-
-  * ``jax_threefry_partitionable``: newer jax defaults this to True; on the
-    0.4.x CI pin it still defaults to False, under which ``jax.random``
-    values depend on the output *sharding* — the same seed would initialize
-    different weights on different meshes, breaking elastic rescale and the
-    sharded==unsharded equivalence tests.  Force the modern behavior.
+Importing it does not import JAX, so an entry point can configure JAX
+(``repro.utils.compile_cache``) before the first compile.
 """
-
-import jax as _jax
-
-if not _jax.config.jax_threefry_partitionable:
-    _jax.config.update("jax_threefry_partitionable", True)

@@ -81,7 +81,6 @@ from repro.core.attention import (AttentionSpec, attention,
                                   topk_softmax_weights)
 from repro.core.binarize import sign_pm1
 from repro.core.topk import NEG_INF, two_stage_topk
-from repro.utils import compat
 
 __all__ = [
     "AttentionBackend", "DenseBackend", "BinaryBackend", "CamformerBackend",
@@ -763,7 +762,7 @@ class CamformerBackend(AttentionBackend):
         contextualization is a masked partial sum over local V rows
         finished by one psum.
         """
-        env = compat.get_abstract_mesh()
+        env = jax.sharding.get_abstract_mesh()
         axes = tuple(a for a in ("pod", "data", "model")
                      if a in getattr(env, "shape", {}) and env.shape[a] > 1)
         if not axes:
@@ -826,7 +825,7 @@ class CamformerBackend(AttentionBackend):
             return jax.lax.psum(contrib, axes)
 
         seq_spec = P(None, None, axes, None)
-        out = compat.shard_map(
+        out = jax.shard_map(
             local_fn,
             mesh=env,
             in_specs=(P(), seq_spec,

@@ -23,7 +23,11 @@ Inactive slots point every page-table entry at the reserved trash page 0;
 their scores are fully masked by ``kv_len`` so the garbage never surfaces.
 
 VMEM per cell (defaults page=64, W<=8, R<=8): q 256 B + k 2 KiB + scores
-R*64*4 B ~ 2 KiB + candidates ~KiB  =>  trivially resident.
+R*64*4 B ~ 2 KiB, plus the candidate blocks: each output block is
+(R, 8, ncp) int32 (ncp = stage1_k * page/group candidates per page; eight
+pages' rows), one (8, 128) tile of 4 KiB per query row once lane-padded,
+so two outputs double-buffered take 16 KiB * R (128 KiB at R=8),
+whatever max_len is.
 """
 
 from __future__ import annotations
@@ -36,6 +40,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bacam_topk import score_and_stage1
+
+# logical pages per output block: the block's sublane extent, which the
+# (8, 128) tiling rule wants a multiple of 8
+_PAGES_PER_BLOCK = 8
 
 
 def _kernel(
@@ -67,9 +75,15 @@ def _kernel(
         ok = jnp.logical_and(ok, kpos > qpos - window)
 
     # scoring + stage-1 shared with the contiguous kernel (bacam_topk.py)
-    vals_ref[0, 0], idx_ref[0, 0] = score_and_stage1(
+    vals, idx = score_and_stage1(
         q_ref[0, 0], k_ref[0, 0], ok, d=d, group=group, stage1_k=stage1_k,
         base_offset=j * page)
+    # the (R, 8, ncp) output block holds eight consecutive pages; page j
+    # fills sublane row j % 8 of each query row's candidate table
+    sub = j % _PAGES_PER_BLOCK
+    for r in range(rows):
+        vals_ref[0, 0, r, pl.ds(sub, 1), :] = vals[r:r + 1]
+        idx_ref[0, 0, r, pl.ds(sub, 1), :] = idx[r:r + 1]
 
 
 @functools.partial(
@@ -87,7 +101,7 @@ def bacam_paged_topk_stage1(
     group: int = 16,
     stage1_k: int = 2,
     window: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused paged binary scoring + stage-1 top-k for decode rows.
 
@@ -112,6 +126,8 @@ def bacam_paged_topk_stage1(
     assert words * 32 == d
     assert page % group == 0
     ncp = stage1_k * (page // group)  # candidates per page
+    pb = _PAGES_PER_BLOCK
+    np_out = -(-np_ // pb) * pb  # page rows padded to whole blocks
     grid = (b, hkv, np_)
     kern = functools.partial(
         _kernel,
@@ -127,20 +143,29 @@ def bacam_paged_topk_stage1(
             pl.BlockSpec((1, 1, page, words),
                          lambda b_, h, j, pt, kvl, qp: (pt[b_, j], h, 0, 0)),
         ],
+        # the block's last two dims are (8 pages, ncp): ncp equals the
+        # array's extent and 8 is a multiple of 8, which Mosaic's (8, 128)
+        # tiling rule accepts for a narrow candidate axis.  The block is
+        # written back after every eighth page, so its size does not grow
+        # with max_len.
         out_specs=[
-            pl.BlockSpec((1, 1, rows, ncp),
-                         lambda b_, h, j, pt, kvl, qp: (b_, h, 0, j)),
-            pl.BlockSpec((1, 1, rows, ncp),
-                         lambda b_, h, j, pt, kvl, qp: (b_, h, 0, j)),
+            pl.BlockSpec((1, 1, rows, pb, ncp),
+                         lambda b_, h, j, pt, kvl, qp: (b_, h, 0, j // pb, 0)),
+            pl.BlockSpec((1, 1, rows, pb, ncp),
+                         lambda b_, h, j, pt, kvl, qp: (b_, h, 0, j // pb, 0)),
         ],
     )
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rows, np_ * ncp), jnp.int32),
-            jax.ShapeDtypeStruct((b, hkv, rows, np_ * ncp), jnp.int32),
+            jax.ShapeDtypeStruct((b, hkv, rows, np_out, ncp), jnp.int32),
+            jax.ShapeDtypeStruct((b, hkv, rows, np_out, ncp), jnp.int32),
         ],
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       q_pos.astype(jnp.int32), q_packed, kp_pages)
+    # drop the padding page rows (never written), then the
+    # logical-page-major candidate order is a row-major reshape
+    return (vals[:, :, :, :np_].reshape(b, hkv, rows, np_ * ncp),
+            idx[:, :, :, :np_].reshape(b, hkv, rows, np_ * ncp))

@@ -48,7 +48,7 @@ def bacam_mvm(
     d: int,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Binary scores (B, R, Skv) int32 from packed (B, R, W)/(B, Skv, W).
 

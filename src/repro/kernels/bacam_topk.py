@@ -54,23 +54,33 @@ def score_and_stage1(q_words, k_words, ok, *, d, group, stage1_k,
     scores = jnp.where(ok, jnp.int32(d) - 2 * acc, MASKED_SCORE)
 
     # --- stage-1 top-k per group of `group` keys (bitonic top-2 dual) ---
+    # Sequential max-extraction == stable top-k (lowest index first among
+    # equal scores, as lax.top_k in the oracles).  Built from what Mosaic
+    # lowers: float32 lane reductions (scores are integers with |s| <= d
+    # and MASKED_SCORE = -2**30, so float32 holds every one exactly) and
+    # iota selects; no argmax, no in-kernel reshape.  An extracted key is
+    # knocked out to -inf, BELOW MASKED_SCORE, so a group with fewer than
+    # stage1_k valid keys yields its masked keys in index order too.
     ngroups = bk // group
-    sg = scores.reshape(rows, ngroups, group)
-    gidx = jax.lax.broadcasted_iota(jnp.int32, (rows, ngroups, group), 2)
-    vals, idxs = [], []
-    cur = sg
-    for _ in range(stage1_k):  # sequential max-extraction == stable top-k
-        m = cur.max(axis=-1)
-        am = jnp.argmax(cur, axis=-1).astype(jnp.int32)
-        vals.append(m)
-        idxs.append(am)
-        cur = jnp.where(gidx == am[..., None], MASKED_SCORE, cur)
-    v = jnp.stack(vals, axis=-1).reshape(rows, ngroups * stage1_k)
-    base = (base_offset
-            + jax.lax.broadcasted_iota(jnp.int32, (rows, ngroups), 1) * group)
-    gi = jnp.stack([base + a for a in idxs], axis=-1).reshape(
-        rows, ngroups * stage1_k)
-    return v, gi
+    ncand = ngroups * stage1_k
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+    lane_f = lane.astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, ncand), 1)
+    cur = scores.astype(jnp.float32)
+    vals = jnp.zeros((rows, ncand), jnp.int32)
+    idxs = jnp.zeros((rows, ncand), jnp.int32)
+    for g in range(ngroups):
+        in_g = jnp.logical_and(lane >= g * group, lane < (g + 1) * group)
+        for t in range(stage1_k):
+            sg = jnp.where(in_g, cur, -jnp.inf)
+            m = sg.max(axis=-1, keepdims=True)  # (R, 1)
+            am = jnp.where(sg == m, lane_f, jnp.float32(bk)).min(
+                axis=-1, keepdims=True)  # lowest lane holding the max
+            here = col == g * stage1_k + t  # group-major / top-k-minor
+            vals = jnp.where(here, m.astype(jnp.int32), vals)
+            idxs = jnp.where(here, am.astype(jnp.int32) + base_offset, idxs)
+            cur = jnp.where(lane_f == am, -jnp.inf, cur)
+    return vals, idxs
 
 
 def _kernel(
@@ -125,7 +135,7 @@ def bacam_topk_stage1(
     window: int | None = None,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused binary scores + stage-1 top-k.
 

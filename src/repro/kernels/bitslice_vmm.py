@@ -33,7 +33,7 @@ def bitslice_vmm(
     bits: int,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """y = x_pm1 @ w_int^T via per-slice BA-CAM searches.
 

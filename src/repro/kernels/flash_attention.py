@@ -87,7 +87,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Flash attention forward. q: (B, Sq, D); k, v: (B, Skv, D)."""
     b, sq, d = q.shape

@@ -1,8 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 These present kernel functionality with framework-friendly shapes (padding,
-GQA folding, batch flattening) and select interpret mode automatically:
-interpret=True off-TPU (this container), compiled Mosaic on real TPU.
+GQA folding, batch flattening) and pick the realization per call, when the
+wrapper is traced (never at import): compiled Mosaic on a TPU backend;
+off-TPU the flash kernels run their jnp page walk and the CAM kernels the
+Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -21,7 +23,6 @@ from repro.kernels import paged_flash_decode as _pfd
 from repro.kernels.ref import MASKED_SCORE
 
 __all__ = [
-    "INTERPRET",
     "bacam_scores",
     "bacam_attention_scores_topk",
     "bacam_attention_scores_topk_packed",
@@ -33,7 +34,11 @@ __all__ = [
     "MASKED_SCORE",
 ]
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """True off-TPU: decided when a wrapper is traced, so importing this
+    module never starts a backend."""
+    return jax.default_backend() != "tpu"
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -66,7 +71,8 @@ def bacam_scores(qb: jax.Array, kb: jax.Array, *, block_q=256, block_k=512) -> j
     bk = _pick_block(skv, block_k)
     qp = _pad_axis(pack_bits(qb), 1, _ceil_to(r, bq))
     kp = _pad_axis(pack_bits(kb), 1, _ceil_to(skv, bk))
-    s = _mvm.bacam_mvm(qp, kp, d=d, block_q=bq, block_k=bk, interpret=INTERPRET)
+    s = _mvm.bacam_mvm(qp, kp, d=d, block_q=bq, block_k=bk,
+                       interpret=_interpret())
     return s[:, :r, :skv]
 
 
@@ -127,7 +133,7 @@ def bacam_attention_scores_topk_packed(
     vals, idx = _btk.bacam_topk_stage1(
         qp, kp, pos, kvl,
         d=d, group=group, stage1_k=stage1_k, causal=causal, window=window,
-        block_q=bq, block_k=bk, interpret=INTERPRET,
+        block_q=bq, block_k=bk, interpret=_interpret(),
     )
     ncand = stage1_k * (-(-skv // group))
     vals = vals[:, :r, :ncand]
@@ -165,7 +171,7 @@ def bacam_paged_scores_topk(
     vals, idx = _bdec.bacam_paged_topk_stage1(
         qp, kp_pages, page_table, kv_len, q_pos,
         d=d, group=group, stage1_k=stage1_k, window=window,
-        interpret=INTERPRET,
+        interpret=_interpret(),
     )
     fvals = jnp.where(vals <= MASKED_SCORE // 2, NEG_INF,
                       vals.astype(jnp.float32))
@@ -211,7 +217,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, kv_len, q_pos, *,
     qr = qr * jnp.float32(scale)
     if temp is not None:
         qr = qr * temp.reshape(b, hkv, g, 1).astype(jnp.float32)
-    if interpret is not None or not INTERPRET:
+    if interpret is not None or not _interpret():
         # explicit interpret=True/False forces the Pallas kernel in that
         # mode; interpret=None on TPU runs it compiled
         out = _pfd.paged_flash_decode(
@@ -267,7 +273,7 @@ def paged_flash_prefill(q, k_pages, v_pages, page_table, kv_len, q_pos, *,
         # (B, H_kv, G*Sq) row-major (g, s) — matches the row fold below
         qr = qr * temp.reshape(b, hkv, g, sq, 1).astype(jnp.float32)
     qr = qr.reshape(b, hkv, g * sq, d)  # row r = g_idx * sq + s
-    if interpret is not None or not INTERPRET:
+    if interpret is not None or not _interpret():
         out = _pfd.paged_flash_decode(
             qr, k_pages, v_pages, page_table, kv_len.reshape(b),
             q_pos.reshape(b), sq=sq, binary=binary, window=window,
@@ -300,7 +306,7 @@ def flash_attention(q, k, v, q_offset=0, *, causal=True, window=None, scale=None
         raise ValueError("non-causal flash requires Skv % block_k == 0")
     out = _fla.flash_attention(
         qq, kk, vv, q_offset, causal=causal, window=window, scale=scale,
-        block_q=bq, block_k=bk, interpret=INTERPRET,
+        block_q=bq, block_k=bk, interpret=_interpret(),
     )
     return out[:, :sq]
 
@@ -313,5 +319,6 @@ def bitslice_vmm(x_pm1, w_int, *, bits, block_q=256, block_k=512):
     bk = _pick_block(n, block_k)
     x = _pad_axis(x_pm1, 1, _ceil_to(r, bq), value=1)
     w = _pad_axis(w_int, 1, _ceil_to(n, bk))
-    y = _bsv.bitslice_vmm(x, w, bits=bits, block_q=bq, block_k=bk, interpret=INTERPRET)
+    y = _bsv.bitslice_vmm(x, w, bits=bits, block_q=bq, block_k=bk,
+                          interpret=_interpret())
     return y[:, :r, :n]

@@ -41,9 +41,10 @@ a defined all-zeros vector that the engine never reads.  Inactive
 page-table entries point at the reserved trash page 0; kv_len masking
 keeps its garbage out of every live row's softmax.
 
-Interpret-mode escape hatch: pass ``interpret=True`` (the ops wrapper
-does this automatically off-TPU) to run the kernel through the Pallas
-interpreter for CPU CI debugging — same semantics, XLA-compiled grid.
+``interpret`` has no default: ``False`` compiles the kernel for the TPU,
+``True`` runs it through the Pallas interpreter (CPU debugging — same
+semantics).  Off-TPU the ops wrapper runs the jnp page walk
+(ref.paged_flash_decode_ref) instead of either.
 
 VMEM per cell (defaults page=64, D<=256, R<=8): k/v tiles
 2*64*256*4 B = 128 KiB + q/acc ~ 2*8*256*4 B ~ 16 KiB  =>  resident.
@@ -160,7 +161,7 @@ def paged_flash_decode(
     sq: int = 1,
     binary: bool = False,
     window: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused paged flash-decode over one layer's K/V page pools.
 
@@ -183,7 +184,8 @@ def paged_flash_decode(
         chunk's FIRST position (the slot's ``offsets``).
       sq: chunk length folded into the row axis (static).
       binary: binarize the K tile in-register (HAD sign-match scoring).
-      interpret: run via the Pallas interpreter (CPU CI escape hatch).
+      interpret: False compiles for the TPU; True runs the Pallas
+        interpreter.
 
     Returns:
       (B, H_kv, R, Dv) float32 attention outputs; inert rows are zeros.

@@ -134,10 +134,8 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, backend=None,
     cfg, md, fn, args, n_params, note = build_cell(arch, shape, mesh,
                                                    backend, dist_topk,
                                                    prefill_chunk)
-    from repro.utils import compat
-
-    compat.set_mesh(mesh)  # installs the ambient mesh for constrain()
-    with mesh:
+    # the ambient mesh for constrain(), for this cell only
+    with jax.set_mesh(mesh), mesh:
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -30,6 +30,7 @@ from repro.models import get_model_def
 from repro.models.module import init_params
 from repro.serving import ServeEngine, parse_faults
 from repro.serving.gateway import Gateway
+from repro.utils import compile_cache
 
 
 def build_engine(args) -> ServeEngine:
@@ -122,6 +123,7 @@ def main() -> None:
         "single-device engine, same code path",
     )
     args = ap.parse_args()
+    compile_cache.enable()
 
     engine = build_engine(args)
     layout = engine.cfg.uniform_backend or ",".join(engine.cfg.layer_backends)

@@ -19,6 +19,7 @@ the pool drains).
 """
 
 import argparse
+import sys
 
 import jax
 
@@ -27,6 +28,7 @@ from repro.launch.cli import add_backend_args, apply_backend_args
 from repro.models import get_model_def
 from repro.models.module import init_params
 from repro.serving import Request, SamplingParams, ServeEngine, parse_faults
+from repro.utils import compile_cache
 
 
 def main():
@@ -97,6 +99,7 @@ def main():
     ap.add_argument("--no-stream", action="store_true",
                     help="suppress per-token output, print only summaries")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = apply_backend_args(cfg, args)
@@ -142,8 +145,8 @@ def main():
           f"{eng.readbacks} readbacks, host idle "
           f"{eng.blocked_s / max(wall, 1e-9):.0%}")
     if eng.tick_errors:
-        print(f"chaos: {eng.tick_errors} tick errors contained "
-              f"(last: {eng.last_error}), "
+        print(f"{'chaos' if faults else 'ERROR'}: {eng.tick_errors} tick "
+              f"errors contained (last: {eng.last_error}), "
               f"{eng.sched.timeouts} timeouts, "
               f"{eng.sched.rejections} rejections")
     if eng.spec_k:
@@ -156,6 +159,8 @@ def main():
     for r in sorted(eng.done, key=lambda r: r.rid):
         print(f"req {r.rid}: prompt[{len(r.prompt)}] "
               f"prefix_hit={r.prefix_matched} -> {r.tokens}")
+    if eng.tick_errors and not faults:
+        sys.exit(1)  # device ticks failed with no fault plan: a real fault
 
 
 if __name__ == "__main__":

@@ -28,7 +28,6 @@ from repro.core.backend import AttentionBackend, get_backend
 from repro.models.layers import rope
 from repro.models.module import Param
 from repro.sharding.partitioning import constrain
-from repro.utils import compat
 
 __all__ = [
     "attn_specs", "attn_cache_spec", "attention_block",
@@ -112,7 +111,7 @@ def _attn_strategy(cfg, training: bool = True) -> str:
                rematerialization, kv_seq keeps bwd local modulo small
                softmax-stat reduces + the AV partial-sum all-reduce.
     """
-    env = compat.get_abstract_mesh()
+    env = jax.sharding.get_abstract_mesh()
     if env is None or "model" not in getattr(env, "shape", {}):
         return "none"
     m = env.shape["model"]

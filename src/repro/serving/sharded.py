@@ -44,8 +44,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.utils import compat
-
 __all__ = ["HEAD_AXIS", "TP_AXIS", "leaf_partition_spec",
            "pool_partition_specs", "shard_pools", "replicate", "shard_step"]
 
@@ -120,15 +118,12 @@ def replicate(tree, mesh):
 def shard_step(fn, mesh, in_specs, out_specs):
     """shard_map a fused engine step over the tp mesh.
 
-    ``check_rep=False`` because the body's replication cannot be
-    statically inferred through ``all_gather`` on jax 0.4.x (the outputs
-    ARE replicated — the gather reconstructs identical full-head
-    activations on every device; the identity tests assert it).  Newer
-    jax versions that drop the kwarg fall through to the plain call.
+    ``check_vma=False``: the varying-axes check cannot see that the
+    ``all_gather`` in models/attention.py makes the attention output
+    replicated again, and the layer scan then refuses a carry it types
+    as varying.  The outputs ARE replicated — the gather reconstructs
+    identical full-head activations on every device, which the identity
+    tests assert.
     """
-    try:
-        return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

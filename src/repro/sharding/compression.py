@@ -43,9 +43,7 @@ def compressed_psum_leaf(g: jax.Array, err: jax.Array, axis: str):
     (n-1)/n * n * 1 B  vs  2 * 4 B per element.
 
     Returns (mean gradient f32, new error accumulator)."""
-    from repro.utils import compat
-
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     x = g.astype(jnp.float32) + err
     q, scale = quantize_int8(x)
     new_err = x - dequantize_int8(q, scale)  # exact local residual

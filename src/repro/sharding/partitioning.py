@@ -195,9 +195,7 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]],
     No-op unless an ambient mesh is installed (`jax.set_mesh(mesh)` — done
     by the dry-run / trainer / server launchers); models stay mesh-agnostic.
     """
-    from repro.utils import compat
-
-    env = compat.get_abstract_mesh()
+    env = jax.sharding.get_abstract_mesh()
     if env is None or not env.shape:  # no mesh context
         return x
     spec = resolve_spec(logical_axes, x.shape, env, rules)

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import compat
-
 __all__ = ["pipeline_forward"]
 
 
@@ -46,8 +44,8 @@ def pipeline_forward(stage_fn, stage_params, x_micro, mesh, axis: str = "pipe"):
         stage = jax.lax.axis_index(axis)
         # mark carries as axis-varying up front (their values diverge per
         # stage inside the loop) so the fori carry types stay consistent
-        h = compat.pcast(jnp.zeros_like(x_local[0]), (axis,), to="varying")
-        outs = compat.pcast(jnp.zeros_like(x_local), (axis,), to="varying")
+        h = jax.lax.pcast(jnp.zeros_like(x_local[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(x_local), (axis,), to="varying")
 
         def tick(t, carry):
             h, outs = carry
@@ -77,7 +75,7 @@ def pipeline_forward(stage_fn, stage_params, x_micro, mesh, axis: str = "pipe"):
             axis)
         return outs
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

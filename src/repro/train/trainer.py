@@ -81,9 +81,11 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def run(self):
-        from repro.utils import compat
+        # the ambient mesh (constrain()) lasts for the run, not the process
+        with jax.set_mesh(self.mesh):
+            return self._run()
 
-        compat.set_mesh(self.mesh)
+    def _run(self):
         state, start = self.restore_or_init()
         times = []
         step = start

@@ -44,10 +44,8 @@ SHAPES["tiny"] = dict(seq_len=64, global_batch=8, kind="train")
 cfg = smoke_config("granite-moe-3b-a800m").replace(n_experts_padded=8)
 md = get_model_def(cfg)
 
-from repro.utils import compat
-
 def run(mesh):
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     step, opt = make_train_step(md, cfg, warmup=1)
     sds, shard = state_specs(md, cfg, mesh)
     params = jax.jit(lambda k: init_params(md.specs(cfg), k),
@@ -107,8 +105,8 @@ import jax, jax.numpy as jnp
 from repro.launch.mesh import make_mesh_for
 from repro.sharding.pipeline import pipeline_forward
 
-from repro.utils import compat
-mesh = compat.make_mesh((4,), ("pipe",), axis_types=compat.axis_type_auto(1))
+mesh = jax.make_mesh((4,), ("pipe",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 S, MB, D = 4, 3, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (S, D, D)) / D**0.5
@@ -135,8 +133,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.sharding.compression import compressed_psum_leaf, compressed_mean_ref
 
-from repro.utils import compat
-mesh = compat.make_mesh((4,), ("pod",), axis_types=compat.axis_type_auto(1))
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))  # per-pod grads
 errs = jnp.zeros_like(g)
 
@@ -144,8 +142,8 @@ def f(g_local, e_local):
     m, ne = compressed_psum_leaf(g_local[0], e_local[0], "pod")
     return m[None], ne[None]
 
-fn = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                              out_specs=(P("pod"), P("pod"))))
+fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                           out_specs=(P("pod"), P("pod"))))
 mean_est, new_err = fn(g, errs)
 ref_mean, ref_err = compressed_mean_ref(g, errs)
 # every pod computed the same mean estimate; matches the reference exactly
@@ -188,9 +186,8 @@ from repro.core.backend import get_backend
 from repro.core import bacam, sign_pm1
 from repro.launch.mesh import make_mesh_for
 
-from repro.utils import compat
 mesh = make_mesh_for(4, 2)  # data=2, model=2
-compat.set_mesh(mesh)
+jax.set_mesh(mesh)
 cfg = smoke_config("codeqwen1.5-7b", head_dim=128, n_heads=4,
                    n_kv_heads=2).replace(attn_backend="camformer", k_top=8,
                                          group_size=4, stage1_k=2)
