@@ -378,59 +378,61 @@ def camformer_paged_attention(
     np_ = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    qb = sign_pm1(q.astype(jnp.float32))
-    q_scale = jnp.mean(jnp.abs(q.astype(jnp.float32)), axis=-1)  # (B,H,Sq)
-    qp = bacam.pack_bits(qb).reshape(b, hkv, g * sq, d // 32)
-    kv_len = kv_len.reshape(b).astype(jnp.int32)
+    with jax.named_scope("cam_search"):
+        qb = sign_pm1(q.astype(jnp.float32))
+        q_scale = jnp.mean(jnp.abs(q.astype(jnp.float32)), axis=-1)  # (B,H,Sq)
+        qp = bacam.pack_bits(qb).reshape(b, hkv, g * sq, d // 32)
+        kv_len = kv_len.reshape(b).astype(jnp.int32)
 
-    if sq == 1 and impl == "fused":
-        # Decode fast path: fused paged scoring + stage-1 top-k kernel.
-        from repro.kernels import ops as kops  # local import: no cycle
+        if sq == 1 and impl == "fused":
+            # Decode fast path: fused paged scoring + stage-1 top-k kernel.
+            from repro.kernels import ops as kops  # local import: no cycle
 
-        cand_v, cand_i = kops.bacam_paged_scores_topk(
-            qp, kp_pages, page_table, kv_len,
-            q_positions.reshape(b).astype(jnp.int32),
-            d=d, group=spec.group_size, stage1_k=spec.stage1_k,
-            window=window)
-        k_eff = min(spec.k_top, cand_v.shape[-1])
-        top_v, sel = jax.lax.top_k(cand_v, k_eff)
-        top_i = jnp.take_along_axis(cand_i, sel, axis=-1)
-    else:
-        # Prefill chunk: gather packed key pages into logical order.
-        from repro.kernels.ref import paged_gather_ref
+            cand_v, cand_i = kops.bacam_paged_scores_topk(
+                qp, kp_pages, page_table, kv_len,
+                q_positions.reshape(b).astype(jnp.int32),
+                d=d, group=spec.group_size, stage1_k=spec.stage1_k,
+                window=window)
+            k_eff = min(spec.k_top, cand_v.shape[-1])
+            top_v, sel = jax.lax.top_k(cand_v, k_eff)
+            top_i = jnp.take_along_axis(cand_i, sel, axis=-1)
+        else:
+            # Prefill chunk: gather packed key pages into logical order.
+            from repro.kernels.ref import paged_gather_ref
 
-        kp = paged_gather_ref(kp_pages, page_table)  # (B, H_kv, S_log, W)
-        scores = bacam.hamming_scores_packed(qp, kp, d)  # (B,Hkv,G*Sq,S)
-        kpos = jnp.arange(np_ * page, dtype=jnp.int32)[None, None, None]
-        qpos = jnp.broadcast_to(q_positions[:, None, :], (b, hkv, sq))
-        qpos = jnp.broadcast_to(qpos[:, :, None, :], (b, hkv, g, sq)).reshape(
-            b, hkv, g * sq)[..., None]
-        ok = (kpos < kv_len.reshape(b, 1, 1, 1)) & (kpos <= qpos)
-        if window is not None:
-            ok = ok & (kpos > qpos - window)
-        masked = jnp.where(ok, scores.astype(jnp.float32), NEG_INF)
-        top_v, top_i = two_stage_topk(
-            masked, k=spec.k_top, group_size=spec.group_size,
-            stage1_k=spec.stage1_k)
+            kp = paged_gather_ref(kp_pages, page_table)  # (B, H_kv, S_log, W)
+            scores = bacam.hamming_scores_packed(qp, kp, d)  # (B,Hkv,G*Sq,S)
+            kpos = jnp.arange(np_ * page, dtype=jnp.int32)[None, None, None]
+            qpos = jnp.broadcast_to(q_positions[:, None, :], (b, hkv, sq))
+            qpos = jnp.broadcast_to(qpos[:, :, None, :], (b, hkv, g, sq)).reshape(
+                b, hkv, g * sq)[..., None]
+            ok = (kpos < kv_len.reshape(b, 1, 1, 1)) & (kpos <= qpos)
+            if window is not None:
+                ok = ok & (kpos > qpos - window)
+            masked = jnp.where(ok, scores.astype(jnp.float32), NEG_INF)
+            top_v, top_i = two_stage_topk(
+                masked, k=spec.k_top, group_size=spec.group_size,
+                stage1_k=spec.stage1_k)
 
-    # --- sparse V contextualization straight from the paged pool ---
-    pg = top_i // page  # logical page of each selected key
-    row = top_i % page
-    phys = page_table[jnp.arange(b)[:, None, None, None], pg]  # (B,Hkv,R,K)
-    v_sel = jax.vmap(  # per-kv-head gather: pool is (P, page, Dv) per head
-        lambda vh, ph, rh: vh[ph, rh], in_axes=(1, 1, 1), out_axes=1
-    )(v_pages, phys, row)  # (B, H_kv, R, K, Dv)
+    with jax.named_scope("contextualize"):
+        # sparse V contextualization straight from the paged pool
+        pg = top_i // page  # logical page of each selected key
+        row = top_i % page
+        phys = page_table[jnp.arange(b)[:, None, None, None], pg]  # (B,Hkv,R,K)
+        v_sel = jax.vmap(  # per-kv-head gather: pool is (P, page, Dv) per head
+            lambda vh, ph, rh: vh[ph, rh], in_axes=(1, 1, 1), out_axes=1
+        )(v_pages, phys, row)  # (B, H_kv, R, K, Dv)
 
-    # per-slot (B, Hkv) or per-query (B, Hkv, Sq) — see
-    # binary_paged_attention
-    ks = k_scale.astype(jnp.float32)
-    if ks.ndim == 2:
-        ks = ks[:, :, None]
-    ks = jnp.broadcast_to(ks[:, :, None, :], (b, hkv, g, sq))
-    temp = (q_scale.reshape(b, hkv, g * sq)
-            * ks.reshape(b, hkv, g * sq))[..., None]
-    w, _ = topk_softmax_weights(top_v, temp, scale)
-    out = jnp.einsum("bhrk,bhrkd->bhrd", w.astype(v_pages.dtype), v_sel)
+        # per-slot (B, Hkv) or per-query (B, Hkv, Sq) — see
+        # binary_paged_attention
+        ks = k_scale.astype(jnp.float32)
+        if ks.ndim == 2:
+            ks = ks[:, :, None]
+        ks = jnp.broadcast_to(ks[:, :, None, :], (b, hkv, g, sq))
+        temp = (q_scale.reshape(b, hkv, g * sq)
+                * ks.reshape(b, hkv, g * sq))[..., None]
+        w, _ = topk_softmax_weights(top_v, temp, scale)
+        out = jnp.einsum("bhrk,bhrkd->bhrd", w.astype(v_pages.dtype), v_sel)
     return out.reshape(b, h, sq, dv).astype(q.dtype)
 
 

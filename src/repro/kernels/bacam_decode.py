@@ -163,6 +163,7 @@ def bacam_paged_topk_stage1(
             jax.ShapeDtypeStruct((b, hkv, rows, np_out, ncp), jnp.int32),
         ],
         interpret=interpret,
+        name="bacam_paged_topk_stage1",
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       q_pos.astype(jnp.int32), q_packed, kp_pages)
     # drop the padding page rows (never written), then the

@@ -232,6 +232,7 @@ def paged_flash_decode(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, hkv, rows, dv), jnp.float32)],
         interpret=interpret,
+        name="paged_flash_decode",
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       q_pos.astype(jnp.int32), q_rows.astype(jnp.float32), k_pages, v_pages)
     return out

@@ -189,7 +189,12 @@ def attention_block(
     bk = _resolve_backend(cfg, backend)
     b, s, _ = x.shape
     dt = x.dtype
-    q, k, v = _project(p, x, cfg, training=cache is None and cross_kv is None)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _project(p, x, cfg,
+                           training=cache is None and cross_kv is None)
+        if cross_kv is None and getattr(cfg, "use_rope", True):
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
 
     if cross_kv is not None:
         k, v = cross_kv
@@ -197,9 +202,6 @@ def attention_block(
         # encoder keys — the backend applies to cross-attention too.
         out = bk.prefill(q, k, v, cfg, causal=False)
     else:
-        if getattr(cfg, "use_rope", True):
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
         if cache is not None and "v" not in cache and page_table is None:
             # a paged pool (k_pages/kp_pages + v_pages) reached the
             # contiguous path — fail loudly, not with a KeyError below
@@ -219,7 +221,8 @@ def attention_block(
                     base=scale_base)
             out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.q_dim)
             out = constrain(out, ("batch", "seq", "heads"))
-            return (out @ p["wo"].astype(dt)), new_cache
+            with jax.named_scope("attn_proj"):
+                return (out @ p["wo"].astype(dt)), new_cache
         index = cache_index if cache_index is not None else jnp.int32(0)
         if cache is not None and kv_len is not None:
             # decode / cached path: attend over the (partially valid) cache
@@ -241,4 +244,5 @@ def attention_block(
         out = constrain(out, ("batch", "att_q_seq", "heads"))
     else:
         out = constrain(out, ("batch", "seq", "heads"))
-    return (out @ p["wo"].astype(dt)), cache
+    with jax.named_scope("attn_proj"):
+        return (out @ p["wo"].astype(dt)), cache

@@ -78,11 +78,12 @@ def _apply_block(p, x, cfg, *, positions, cache=None, cache_index=None,
         kv_len=kv_len, page_table=page_table, scale_base=scale_base,
         causal=causal, backend=backend)
     x = constrain(x + h, ("batch", "res_seq", "embed"))
-    ff_in = L.apply_norm(p["ln2"], x, cfg)
-    if cfg.n_experts:
-        ff, aux = M.apply_moe(p["ffn"], ff_in, cfg)
-    else:
-        ff, aux = L.apply_mlp(p["ffn"], ff_in, cfg), {}
+    with jax.named_scope("mlp"):
+        ff_in = L.apply_norm(p["ln2"], x, cfg)
+        if cfg.n_experts:
+            ff, aux = M.apply_moe(p["ffn"], ff_in, cfg)
+        else:
+            ff, aux = L.apply_mlp(p["ffn"], ff_in, cfg), {}
     x = constrain(x + ff, ("batch", "res_seq", "embed"))
     return x, new_cache, aux
 
@@ -168,7 +169,8 @@ def lm_hidden(params, tokens, cfg, *, positions=None, caches=None,
               scale_base=None, causal=True, prefix_embeds=None):
     """tokens (B, S) -> final hidden states (B, S[+P], d)."""
     dt = dtype_of(cfg)
-    x = L.embed_lookup(params["embed"], tokens, cfg, dt)
+    with jax.named_scope("embed"):
+        x = L.embed_lookup(params["embed"], tokens, cfg, dt)
     if prefix_embeds is not None:  # VLM: prepend patch embeddings
         x = jnp.concatenate([prefix_embeds.astype(dt), x], axis=1)
     x = constrain(x, ("batch", "res_seq", "embed"))
@@ -404,6 +406,7 @@ def _last_logits(params, x, cfg):
     return _head_logits(params, x[:, -1], cfg)
 
 
+@jax.named_scope("lm_head")
 def _all_logits(params, x, cfg):
     """Vocabulary logits for every position of x (B, S, d) -> (B, S, V)."""
     dt = x.dtype
@@ -419,6 +422,7 @@ def _all_logits(params, x, cfg):
     return logits
 
 
+@jax.named_scope("lm_head")
 def _head_logits(params, last, cfg):
     """Vocabulary logits for per-slot final hidden states last (B, d)."""
     dt = last.dtype

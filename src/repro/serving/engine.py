@@ -44,6 +44,7 @@ sharing, and LRU prefix retention; see that module.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Iterator, List, NamedTuple, Optional, Tuple
@@ -51,6 +52,7 @@ from typing import Iterator, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec
 
 from repro.launch.mesh import make_tp_mesh
@@ -74,7 +76,7 @@ log = logging.getLogger("repro.serving.engine")
 PREFILL_BUCKET = 16
 
 
-def _copy_pool_page(caches, src, dst):
+def fork_page(caches, src, dst):
     """Copy physical page ``src`` -> ``dst`` across every layer's page
     pools (the device half of a COW fork).  Page leaves are recognized by
     the ``*_pages`` naming contract of ``AttentionBackend.page_spec``;
@@ -105,6 +107,7 @@ class _InFlight(NamedTuple):
     #                                   (B, m+1) spec pack (samples ++ n_valid)
     decode_emit: Tuple[Emit, ...]
     spec: bool = False  # decode_tok is a speculative multi-token pack
+    tick: int = 0  # the engine's ``ticks`` when this tick was dispatched
 
     @property
     def empty(self) -> bool:
@@ -230,7 +233,7 @@ class ServeEngine:
         self._decode_jits = {}  # hot -> jitted fused decode step
         self._spec_jits = {}  # hot -> jitted fused draft+verify step
         if tp == 1:
-            self._fork = jax.jit(_copy_pool_page)
+            self._fork = jax.jit(fork_page)
             self._fork_draft = self._fork
         else:
             # the COW fork copies along the PAGE axis, never the head
@@ -239,11 +242,11 @@ class ServeEngine:
             # their spec trees differ (e.g. mixed target, uniform draft)
             R = PartitionSpec()
             self._fork = jax.jit(sharded.shard_step(
-                _copy_pool_page, self.mesh, (self._pool_pspecs, R, R),
+                fork_page, self.mesh, (self._pool_pspecs, R, R),
                 self._pool_pspecs))
             self._fork_draft = None if self._draft_pspecs is None else (
                 jax.jit(sharded.shard_step(
-                    _copy_pool_page, self.mesh, (self._draft_pspecs, R, R),
+                    fork_page, self.mesh, (self._draft_pspecs, R, R),
                     self._draft_pspecs)))
         # double-buffered on-device token state: the decode step's input
         # tokens are the previous step's output, never a host round-trip
@@ -271,6 +274,11 @@ class ServeEngine:
         self.ticks = 0  # decode steps dispatched
         self.tick_errors = 0  # device ticks that failed and were contained
         self.last_error: Optional[str] = None  # most recent contained error
+        # host time of the engine thread, integer ns, at the boundaries of
+        # the engine.plan / engine.dispatch / engine.ingest trace spans
+        self.plan_ns = 0  # Scheduler.plan_tick + take_events
+        self.dispatch_ns = 0  # enqueueing a tick's device steps
+        self.ingest_ns = 0  # settling read tokens, sink callbacks included
 
     # ------------------------------------------------------------------
     # scheduler delegation (host state lives on self.sched)
@@ -374,8 +382,9 @@ class ServeEngine:
                                                   hot)
             else:
 
-                def fn(params, tokens, lens, offsets, scale_base, caches,
-                       pt, keys, index, temps, top_ks, top_ps):
+                def prefill_step(params, tokens, lens, offsets, scale_base,
+                                 caches, pt, keys, index, temps, top_ks,
+                                 top_ps):
                     batch = {"tokens": tokens, "lens": lens,
                              "offsets": offsets, "scale_base": scale_base}
                     logits, caches = md.prefill_paged(params, batch, caches,
@@ -386,6 +395,8 @@ class ServeEngine:
                     else:
                         first = S.greedy(logits)
                     return first, caches
+
+                fn = prefill_step
 
             if self.tp > 1:
                 # (params..scale_base | pools | pt..top_ps) -> (first, pools)
@@ -408,9 +419,9 @@ class ServeEngine:
         if hot not in self._decode_jits:
             md, cfg = self.md, self.cfg
 
-            def fn(params, tok_prev, fresh, fresh_mask, live_mask, pos,
-                   kv_len, caches, pt, base, keys, index, temps, top_ks,
-                   top_ps):
+            def decode_step(params, tok_prev, fresh, fresh_mask, live_mask,
+                            pos, kv_len, caches, pt, base, keys, index, temps,
+                            top_ks, top_ps):
                 # merge the double-buffered token state on-device: rows
                 # that finished prefill THIS tick take their freshly
                 # sampled first token, continuing rows take the previous
@@ -427,14 +438,53 @@ class ServeEngine:
                     nxt = S.greedy(logits)
                 return nxt, caches
 
+            fn = decode_step
             if self.tp > 1:
                 # (params..kv_len | pools | pt..top_ps) -> (nxt, pools)
                 fn = self._shardify(fn, 7, 7)
             self._decode_jits[hot] = jax.jit(fn)
         return self._decode_jits[hot]
 
+    # ------------------------------------------------------------------
+    # host-side spans and counters of one tick
+    # ------------------------------------------------------------------
+    @contextlib.contextmanager
+    def _timed(self, span: str, counter: str, **args):
+        """Time a leaf of the engine thread's tick: a profiler span named
+        ``span`` (on the device trace's clock while a trace runs) and the
+        integer-ns counter ``counter`` (always)."""
+        t0 = time.perf_counter_ns()
+        try:
+            with TraceAnnotation(span, **args):
+                yield
+        finally:
+            setattr(self, counter,
+                    getattr(self, counter) + time.perf_counter_ns() - t0)
+
+    def _plan(self, **kw) -> Tuple[TickPlan, List[RequestOutput]]:
+        """``Scheduler.plan_tick`` plus the events it expired."""
+        with self._timed("engine.plan", "plan_ns", tick=self.ticks):
+            plan = self.sched.plan_tick(**kw)
+            return plan, self.sched.take_events()
+
+    def _step_names(self, plan: TickPlan) -> str:
+        """The jitted steps a plan enqueues, as their modules are named."""
+        spec = "spec_" if self.spec_k else ""
+        names = ["fork_page"] if plan.forks else []
+        if plan.prefill is not None:
+            names.append(spec + "prefill_step")
+        if plan.decode is not None:
+            names.append("spec_step" if spec else "decode_step")
+        return "+".join(names)
+
     def _dispatch(self, plan: TickPlan) -> _InFlight:
         """Enqueue one tick's device work; returns unread token handles."""
+        with self._timed("engine.dispatch", "dispatch_ns", tick=self.ticks,
+                         steps=self._step_names(plan)):
+            return self._enqueue(plan)
+
+    def _enqueue(self, plan: TickPlan) -> _InFlight:
+        tick = self.ticks
         self.faults.raise_if("step.error")  # chaos: the fused step dies
         for src, dst in plan.forks:  # COW copies BEFORE any write
             self.caches = self._fork(
@@ -500,14 +550,15 @@ class ServeEngine:
                                       self._tok_buf)
         return _InFlight(prefill_tok, pf.emit if pf else (),
                          decode_tok, dc.emit if dc else (),
-                         bool(self.spec_k and dc is not None))
+                         bool(self.spec_k and dc is not None), tick)
 
-    def _read(self, arr: jax.Array) -> np.ndarray:
+    def _read(self, arr: jax.Array, tick: int) -> np.ndarray:
         """THE host<->device readback (token ids only); instrumented so
         benchmarks report the host-idle fraction and tests can assert the
         one-readback-per-tick invariant."""
         t0 = time.perf_counter()
-        out = np.asarray(arr)
+        with TraceAnnotation("engine.readback", tick=tick):
+            out = np.asarray(arr)
         self.blocked_s += time.perf_counter() - t0
         self.readbacks += 1
         return out
@@ -526,30 +577,33 @@ class ServeEngine:
         when a readback raises partway through."""
         events: List[RequestOutput] = []
         self._settling = list(inflight.prefill_emit + inflight.decode_emit)
+        tick = inflight.tick
         if inflight.prefill_emit:
-            vals = self._read(inflight.prefill_tok)
-            for e in inflight.prefill_emit:
-                out = self.sched.ingest(e, int(vals[e.slot]))
-                self._settling.pop(0)
-                if out is not None:
-                    events.append(out)
-        if inflight.decode_emit:
-            vals = self._read(inflight.decode_tok)
-            if inflight.spec:
-                groups: "dict[int, List[Emit]]" = {}
-                for e in inflight.decode_emit:  # slot-major consecutive
-                    groups.setdefault(e.slot, []).append(e)
-                for slot, ems in groups.items():  # insertion == settle order
-                    events.extend(self.sched.resolve_spec(
-                        slot, tuple(ems), vals[slot],
-                        int(vals[slot, -1])))
-                    del self._settling[:len(ems)]
-            else:
-                for e in inflight.decode_emit:
+            vals = self._read(inflight.prefill_tok, tick)
+            with self._timed("engine.ingest", "ingest_ns", tick=tick):
+                for e in inflight.prefill_emit:
                     out = self.sched.ingest(e, int(vals[e.slot]))
                     self._settling.pop(0)
                     if out is not None:
                         events.append(out)
+        if inflight.decode_emit:
+            vals = self._read(inflight.decode_tok, tick)
+            with self._timed("engine.ingest", "ingest_ns", tick=tick):
+                if inflight.spec:
+                    groups: "dict[int, List[Emit]]" = {}
+                    for e in inflight.decode_emit:  # slot-major consecutive
+                        groups.setdefault(e.slot, []).append(e)
+                    for slot, ems in groups.items():  # insertion == settle
+                        events.extend(self.sched.resolve_spec(
+                            slot, tuple(ems), vals[slot],
+                            int(vals[slot, -1])))
+                        del self._settling[:len(ems)]
+                else:
+                    for e in inflight.decode_emit:
+                        out = self.sched.ingest(e, int(vals[e.slot]))
+                        self._settling.pop(0)
+                        if out is not None:
+                            events.append(out)
         return events
 
     # ------------------------------------------------------------------
@@ -610,8 +664,7 @@ class ServeEngine:
         this tick's streamed outputs (empty when the engine is idle)."""
         self.faults.advance()
         self._fault_delay()
-        plan = self.sched.plan_tick()
-        events = self.sched.take_events()  # timeouts expired at plan time
+        plan, events = self._plan()  # events: timeouts expired at plan time
         events.extend(self._run_plan(plan))
         return events
 
@@ -625,8 +678,8 @@ class ServeEngine:
         events: List[RequestOutput] = []
         while self.sched.has_prefilling:
             self.faults.advance()
-            plan = self.sched.plan_tick(admit=False, decode=False)
-            events.extend(self.sched.take_events())
+            plan, expired = self._plan(admit=False, decode=False)
+            events.extend(expired)
             events.extend(self._run_plan(plan))
         return events
 
@@ -661,8 +714,7 @@ class ServeEngine:
         inflight = None
         events: List[RequestOutput] = []
         if self.has_work:
-            plan = self.sched.plan_tick()
-            events.extend(self.sched.take_events())
+            plan, events = self._plan()
             try:
                 inflight = self._dispatch(plan)
             except Exception as e:

@@ -24,6 +24,7 @@ Architecture — one engine thread, many asyncio clients:
     token) and TPOT (inter-token) histograms on the engine thread, plus
     request/token/prefix-sharing counters.  ``GET /metrics`` surfaces
     them next to the engine's own counters (``readbacks``, ``blocked_s``,
+    host seconds in ``plan_s`` / ``dispatch_s`` / ``ingest_s``,
     ``peak_pages``, ``preemptions``, pool residency).
 
 Endpoints:
@@ -56,6 +57,8 @@ import logging
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.faults import NO_FAULTS, FaultPlan
 from repro.serving.request import Request, RequestOutput, RequestState, SamplingParams
@@ -147,9 +150,6 @@ class GatewayMetrics:
         self.tokens_out = 0
         self.prompt_tokens = 0
         self.prefix_hit_tokens = 0
-        # routed (rid, index) order — the continuous-batching interleave
-        # record the gateway tests assert on; bounded for long-lived servers
-        self.event_log = collections.deque(maxlen=4096)
 
     def record_submit(self) -> None:
         with self._lock:
@@ -166,7 +166,6 @@ class GatewayMetrics:
         with self._lock:
             if out.token is not None:
                 self.tokens_out += 1
-                self.event_log.append((out.rid, out.index))
                 if rec["t_prev"] is None:
                     self.ttft.observe((now - rec["t_submit"]) * 1e3)
                 else:
@@ -356,7 +355,8 @@ class EngineRunner(threading.Thread):
     def run(self) -> None:
         eng = self.engine
         while not self._stopping.is_set():
-            self._drain_control()
+            with TraceAnnotation("engine.drain", tick=eng.ticks):
+                self._drain_control()
             try:
                 eng.poll()
             except Exception as e:
@@ -375,7 +375,9 @@ class EngineRunner(threading.Thread):
                     log.exception("containment itself failed")
                 self._wake.wait(self.idle_wait_s)
             if not (eng.has_work or eng.has_pending):
-                if self._wake.wait(self.idle_wait_s):
+                with TraceAnnotation("engine.idle", tick=eng.ticks):
+                    woken = self._wake.wait(self.idle_wait_s)
+                if woken:
                     self._wake.clear()
         with self._lock:
             live = list(self._live)
@@ -610,6 +612,11 @@ class Gateway:
             "ticks": eng.ticks,
             "readbacks": eng.readbacks,
             "blocked_s": eng.blocked_s,
+            # engine-thread host time by part of the tick (the ns counters
+            # beside the engine.plan / dispatch / ingest trace spans)
+            "plan_s": eng.plan_ns * 1e-9,
+            "dispatch_s": eng.dispatch_ns * 1e-9,
+            "ingest_s": eng.ingest_ns * 1e-9,
             "peak_pages": eng.peak_pages,
             # per-device page-pool occupancy (ONE host page table; under
             # tensor parallelism each device holds all pages at 1/tp of

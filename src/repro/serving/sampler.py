@@ -27,6 +27,7 @@ __all__ = ["greedy", "sample", "sample_step", "sample_step_keyed",
 _MASKED = -1e9  # filtered logits (matches the vocab-padding mask value)
 
 
+@jax.named_scope("sample")
 def greedy(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -106,6 +107,7 @@ def request_key(seed: int, rid: int) -> np.ndarray:
         2, dtype=np.uint32)
 
 
+@jax.named_scope("sample")
 def sample_step_keyed(logits, keys, index, temperature, top_k, top_p):
     """Per-row keyed sampling for one engine tick (fused into the step).
 

@@ -33,7 +33,7 @@ floating-point summation order and break token-for-token identity.
 
 COW prefix forks and ``truncate_to`` rollback need no new code paths:
 the fork copies pages along the PAGE axis (never the head axis), so the
-same ``_copy_pool_page`` body runs shard_map-wrapped over the sharded
+same ``fork_page`` body runs shard_map-wrapped over the sharded
 pools, and rollback is host page-table arithmetic only.
 """
 

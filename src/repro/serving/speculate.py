@@ -193,8 +193,8 @@ def build_spec_prefill(md, cfg, dcfg, hot: bool):
     drafter-stack prefill over the same chunk batch, so the draft pool
     holds the prompt KV before the slot's first speculative tick."""
 
-    def fn(params, tokens, lens, offsets, scale_base, caches, dcaches, pt,
-           keys, index, temps, top_ks, top_ps):
+    def spec_prefill_step(params, tokens, lens, offsets, scale_base, caches,
+                          dcaches, pt, keys, index, temps, top_ks, top_ps):
         batch = {"tokens": tokens, "lens": lens, "offsets": offsets,
                  "scale_base": scale_base}
         logits, caches = md.prefill_paged(params, batch, caches, pt, cfg)
@@ -206,7 +206,7 @@ def build_spec_prefill(md, cfg, dcfg, hot: bool):
             first = S.greedy(logits)
         return first, caches, dcaches
 
-    return fn
+    return spec_prefill_step
 
 
 def build_spec_step(md, cfg, dcfg, m: int, hot: bool):
@@ -231,8 +231,9 @@ def build_spec_step(md, cfg, dcfg, m: int, hot: bool):
     single readback, tok_buf (B,), caches, dcaches)``.
     """
 
-    def fn(params, tok_prev, fresh, fresh_mask, live_mask, pos, n_tok,
-           caches, dcaches, pt, base, keys, index, temps, top_ks, top_ps):
+    def spec_step(params, tok_prev, fresh, fresh_mask, live_mask, pos, n_tok,
+                  caches, dcaches, pt, base, keys, index, temps, top_ks,
+                  top_ps):
         pos = pos.astype(jnp.int32)
         n_tok = n_tok.astype(jnp.int32)
         index = index.astype(jnp.int32)
@@ -282,4 +283,4 @@ def build_spec_step(md, cfg, dcfg, m: int, hot: bool):
         packed = jnp.concatenate([samples, n_valid[:, None]], axis=1)
         return packed, tok_buf, caches, dcaches
 
-    return fn
+    return spec_step

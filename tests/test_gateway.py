@@ -139,6 +139,9 @@ def test_gateway_healthz_metrics_and_validation():
         assert metrics["engine"]["ticks"] > 0
         assert metrics["engine"]["preemptions"] == 0
         assert metrics["engine"]["pool_pages"] > 0
+        # the engine thread's host seconds by part of the tick
+        for part in ("plan_s", "dispatch_s", "ingest_s"):
+            assert metrics["engine"][part] > 0, part
         # speculative-decode counters are always surfaced (0 with the
         # plain loop; nonzero acceptance books when spec_k > 0)
         assert metrics["engine"]["spec_proposed"] == 0
@@ -170,6 +173,17 @@ def test_concurrent_clients_interleave():
     handle = serve_background(_engine(_cfg("dense")))
     n_clients, results = 3, {}
     barrier = threading.Barrier(n_clients)
+    # the engine-thread routing order, recorded on the runner's sink path
+    routed, submit = [], handle.runner.submit
+
+    def spy_submit(req, sink):
+        def spy(out):
+            if out.token is not None:
+                routed.append((out.rid, out.index))
+            sink(out)
+        return submit(req, spy)
+
+    handle.runner.submit = spy_submit
 
     def client(i):
         barrier.wait()
@@ -195,7 +209,7 @@ def test_concurrent_clients_interleave():
         # the engine-thread routing order: decode ticks emit one token per
         # live request per tick, so concurrently-resident requests must
         # ALTERNATE in the log rather than complete one after another
-        log = list(handle.runner.metrics.event_log)
+        log = list(routed)
         changes = sum(a[0] != b[0] for a, b in zip(log, log[1:]))
         assert changes > n_clients, (
             f"no continuous-batching interleave in routed order: {log}")

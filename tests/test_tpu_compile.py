@@ -15,6 +15,8 @@ pinned dependency, so a topology that cannot be described fails the
 tests; nothing here skips.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -55,9 +57,13 @@ def _scalars(s, np_=NP):
 
 
 def _assert_kernel(compiled, name):
+    """The compiled program holds the kernel as a ``tpu_custom_call``
+    instruction named after it (``%<name>.<n>``: the kernel's ``name=``,
+    which the device trace's op events carry)."""
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert any(name in ln for ln in calls), calls
+    named = re.compile(rf"^\s*(ROOT )?%{name}(\.\d+)? = ")
+    assert any(named.match(ln) for ln in calls), calls
 
 
 # camformer-bert (GQA group 1, max_len 1024), and a long-context GQA-8
