@@ -97,6 +97,68 @@ def fork_page(caches, src, dst):
     return one(caches, 1)  # uniform: leading `layers` axis
 
 
+def slot_axes(specs):
+    """Per pool leaf, the axis indexed by slot (the ``"batch"`` logical
+    axis of its ``page_spec``: camformer's ``k_scale``, ``k_means``), or
+    None for a leaf indexed by page.  Mirrors the pool tree."""
+
+    def one(layer):
+        return {name: axes.index("batch") if "batch" in axes else None
+                for name, (_, axes) in layer.items()}
+
+    if isinstance(specs, tuple):  # mixed layer_backends: per-layer trees
+        return tuple(one(layer) for layer in specs)
+    return one(specs)
+
+
+def _per_slot(fn, axes, *trees):
+    """Apply ``fn(axis, *leaves)`` to the slot-indexed leaves of pool
+    trees; every other leaf is taken from the last tree."""
+    if isinstance(axes, tuple):
+        return tuple(_per_slot(fn, a, *ts) for a, *ts in zip(axes, *trees))
+    return {k: trees[-1][k] if ax is None else fn(ax, *(t[k] for t in trees))
+            for k, ax in axes.items()}
+
+
+def packed_prefill(step, max_batch: int, axes):
+    """Run a prefill step on a packed batch whose row ``j`` is slot
+    ``slots[j]``.
+
+    ``step(params, tokens, lens, offsets, scale_base, *pools, pt, keys,
+    index, temps, top_ks, top_ps) -> (first, *pools)`` sees every array
+    in row order.  The wrapper takes ``slots`` after ``params``, gathers
+    the slot-indexed pool leaves (``axes``, one tree per pool) and the
+    per-slot sampling arrays to rows on entry, scatters the leaves back
+    on exit, and returns ``first`` in slot order, ``(max_batch,)``.
+    Padding rows carry the out-of-range slot ``max_batch``: their
+    scatters are dropped, so they touch no slot."""
+    n = len(axes)
+
+    def packed(params, slots, tokens, lens, offsets, scale_base, *rest):
+        pools, (pt, keys, index, temps, top_ks, top_ps) = rest[:n], rest[n:]
+
+        def rows(a, axis=0):
+            return jnp.take(a, slots, axis=axis, mode="clip")
+
+        def unrows(axis, old, new):
+            at = (slice(None),) * axis + (slots,)
+            return old.at[at].set(new, mode="drop")
+
+        first, *out = step(
+            params, tokens, lens, offsets, scale_base,
+            *(_per_slot(lambda ax, leaf: rows(leaf, ax), a, p)
+              for a, p in zip(axes, pools)),
+            pt, rows(keys), index, rows(temps), rows(top_ks), rows(top_ps))
+        out = [_per_slot(unrows, a, p, o) for a, p, o in zip(axes, pools, out)]
+        first = jnp.zeros((max_batch,), first.dtype).at[slots].set(
+            first, mode="drop")
+        return (first, *out)
+
+    # the trace names the module after the step (``jit_prefill_step``)
+    packed.__name__ = packed.__qualname__ = step.__name__
+    return packed
+
+
 class _InFlight(NamedTuple):
     """Device handles of one dispatched tick, read back one tick later
     (overlap) or immediately (sync)."""
@@ -206,6 +268,7 @@ class ServeEngine:
         if tp > 1:  # validate head divisibility BEFORE allocating pools
             self._pool_pspecs = sharded.pool_partition_specs(specs, tp)
         self.caches = jax.tree.map(zeros, specs, is_leaf=is_leaf)
+        self._slot_axes = (slot_axes(specs),)  # per pool tree, for prefill
         if tp > 1:  # one NamedSharding per page_spec leaf
             self.caches = sharded.shard_pools(self.caches,
                                               self._pool_pspecs, self.mesh)
@@ -226,6 +289,7 @@ class ServeEngine:
             if tp > 1:
                 self._draft_pspecs = sharded.pool_partition_specs(dspecs, tp)
             self.draft_caches = jax.tree.map(zeros, dspecs, is_leaf=is_leaf)
+            self._slot_axes += (slot_axes(dspecs),)
             if tp > 1:
                 self.draft_caches = sharded.shard_pools(
                     self.draft_caches, self._draft_pspecs, self.mesh)
@@ -338,6 +402,13 @@ class ServeEngine:
         return self.sched.prefill_ticks
 
     @property
+    def prefill_rows(self) -> int:
+        """Rows of the prefill steps dispatched, bucket padding included:
+        1 - prefill_tokens / (prefill_rows x prefill_slice) is the share
+        of prefill rows x positions computed as padding."""
+        return self.sched.prefill_rows
+
+    @property
     def spec_proposed(self) -> int:
         """Draft tokens proposed by the speculative drafter stack."""
         return self.sched.spec_proposed
@@ -398,9 +469,12 @@ class ServeEngine:
 
                 fn = prefill_step
 
+            # the batch holds only the prefilling slots, so jit compiles
+            # the step once per (row bucket, hot)
+            fn = packed_prefill(fn, self.max_batch, self._slot_axes)
             if self.tp > 1:
                 # (params..scale_base | pools | pt..top_ps) -> (first, pools)
-                fn = self._shardify(fn, 5, 6)
+                fn = self._shardify(fn, 6, 6)
             self._prefill_jits[hot] = jax.jit(fn)
         return self._prefill_jits[hot]
 
@@ -479,8 +553,10 @@ class ServeEngine:
 
     def _dispatch(self, plan: TickPlan) -> _InFlight:
         """Enqueue one tick's device work; returns unread token handles."""
+        rows = {} if plan.prefill is None else {
+            "rows": len(plan.prefill.slots)}
         with self._timed("engine.dispatch", "dispatch_ns", tick=self.ticks,
-                         steps=self._step_names(plan)):
+                         steps=self._step_names(plan), **rows):
             return self._enqueue(plan)
 
     def _enqueue(self, plan: TickPlan) -> _InFlight:
@@ -500,21 +576,15 @@ class ServeEngine:
         fresh, fresh_mask = self._zero_tok, None
         pf = plan.prefill
         if pf is not None:
-            if self.spec_k:
-                first, self.caches, self.draft_caches = self._prefill_jit(
-                    pf.hot)(
-                    self.params, jnp.asarray(pf.tokens),
-                    jnp.asarray(pf.lens), jnp.asarray(pf.offsets),
-                    jnp.asarray(pf.scale_base), self.caches,
-                    self.draft_caches, jnp.asarray(pf.table), keys,
-                    jnp.asarray(pf.sample_index), temps, top_ks, top_ps)
-            else:
-                first, self.caches = self._prefill_jit(pf.hot)(
-                    self.params, jnp.asarray(pf.tokens),
-                    jnp.asarray(pf.lens), jnp.asarray(pf.offsets),
-                    jnp.asarray(pf.scale_base), self.caches,
-                    jnp.asarray(pf.table), keys,
-                    jnp.asarray(pf.sample_index), temps, top_ks, top_ps)
+            pools = (self.caches,) + (
+                (self.draft_caches,) if self.spec_k else ())
+            first, self.caches, *draft = self._prefill_jit(pf.hot)(
+                self.params, jnp.asarray(pf.slots), jnp.asarray(pf.tokens),
+                jnp.asarray(pf.lens), jnp.asarray(pf.offsets),
+                jnp.asarray(pf.scale_base), *pools, jnp.asarray(pf.table),
+                keys, jnp.asarray(pf.sample_index), temps, top_ks, top_ps)
+            if draft:
+                (self.draft_caches,) = draft
             if pf.emit:
                 prefill_tok = fresh = first
         dc = plan.decode

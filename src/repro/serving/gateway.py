@@ -629,6 +629,7 @@ class Gateway:
             # decode/queueing problem, rising => prefill path)
             "prefill_tokens": eng.prefill_tokens,
             "prefill_ticks": eng.prefill_ticks,
+            "prefill_rows": eng.prefill_rows,
             "spec_proposed": eng.spec_proposed,
             "spec_accepted": eng.spec_accepted,
             "spec_acceptance": eng.spec_acceptance,

@@ -53,7 +53,7 @@ from repro.serving.kv_cache import (NO_MATCH, TRASH_PAGE, PagedKVCache,
 from repro.serving.request import Request, RequestOutput, RequestState
 
 __all__ = ["Admission", "Emit", "PrefillChunk", "DecodeTick", "TickPlan",
-           "Scheduler", "RejectionError", "QueueFullError"]
+           "Scheduler", "RejectionError", "QueueFullError", "row_bucket"]
 
 
 class RejectionError(ValueError):
@@ -89,18 +89,28 @@ class Emit(NamedTuple):
 
 
 class PrefillChunk(NamedTuple):
-    """One tick's prefill work: each PREFILLING row advances by one chunk
-    of its (suffix of) prompt; rows with ``lens == 0`` are inactive and
-    write to the trash page."""
+    """One tick's prefill work, packed: row ``j < n`` advances PREFILLING
+    slot ``slots[j]`` (slot order) by one chunk of its (suffix of)
+    prompt.  The batch holds R = ``row_bucket(n, max_batch)`` rows; the
+    padding rows past ``n`` have ``lens == 0``, a trashed page table and
+    ``slots == max_batch`` (out of range: the device step drops their
+    per-slot writes)."""
 
-    tokens: np.ndarray  # (B, S) right-padded chunk batch
-    lens: np.ndarray  # (B,) TOTAL valid tokens after this chunk (0 = idle)
-    offsets: np.ndarray  # (B,) first position written this chunk
-    scale_base: np.ndarray  # (B,) k_scale origin (prefix-sharing offset)
-    table: np.ndarray  # (B, P) page-table snapshot, idle rows trashed
-    sample_index: np.ndarray  # (B,) generated-token index sampled per row
+    tokens: np.ndarray  # (R, S) right-padded chunk batch
+    lens: np.ndarray  # (R,) TOTAL valid tokens after this chunk (0 = pad)
+    offsets: np.ndarray  # (R,) first position written this chunk
+    scale_base: np.ndarray  # (R,) k_scale origin (prefix-sharing offset)
+    table: np.ndarray  # (R, P) page-table rows, padding rows trashed
+    sample_index: np.ndarray  # (R,) generated-token index sampled per row
     hot: bool  # any completing row samples with temperature > 0
     emit: Tuple[Emit, ...]  # completing rows: first-token attribution
+    slots: np.ndarray  # (R,) int32 slot of each row, max_batch on padding
+
+
+def row_bucket(n: int, max_batch: int) -> int:
+    """Rows of a prefill batch for ``n`` prefilling slots: the next power
+    of two >= n, capped at ``max_batch`` (one compiled step per bucket)."""
+    return min(1 << max(n - 1, 0).bit_length(), max_batch)
 
 
 class DecodeTick(NamedTuple):
@@ -187,6 +197,7 @@ class Scheduler:
         #  (gateway /metrics + serve_slo: TTFT attribution — a TTFT
         #   regression with flat chunk counters is a decode/queue problem,
         #   not a prefill-path one)
+        self.prefill_rows = 0  # rows of those chunk batches, padding included
         # slots whose multi-token tick is dispatched but not yet resolved
         # (rollback may rewind their pos/dispatched/pages): excluded from
         # planning and drain until resolve_spec runs.  Keyed by slot,
@@ -477,26 +488,32 @@ class Scheduler:
                     self.max_len)
         else:
             s = min(self.prefill_slice, self.max_len)
-        tokens = np.zeros((b, s), np.int32)
-        lens = np.zeros(b, np.int32)
-        offsets = np.zeros(b, np.int32)
-        scale_base = np.zeros(b, np.int32)
-        sample_index = np.zeros(b, np.int32)
+        rows = row_bucket(len(slots), b)
+        tokens = np.zeros((rows, s), np.int32)
+        lens = np.zeros(rows, np.int32)
+        offsets = np.zeros(rows, np.int32)
+        scale_base = np.zeros(rows, np.int32)
+        sample_index = np.zeros(rows, np.int32)
+        row_slots = np.full(rows, b, np.int32)
+        table = np.full((rows, self.kv.table.shape[1]), TRASH_PAGE,
+                        self.kv.table.dtype)
         emit: List[Emit] = []
         hot = False
-        for i in slots:
+        for j, i in enumerate(slots):
             r = self.active[i]
             chunk = chunks[i]
-            tokens[i, :len(chunk)] = chunk
-            offsets[i] = self.progress[i]
-            scale_base[i] = self.base[i]
-            lens[i] = self.progress[i] + len(chunk)
+            row_slots[j] = i
+            tokens[j, :len(chunk)] = chunk
+            offsets[j] = self.progress[i]
+            scale_base[j] = self.base[i]
+            lens[j] = self.progress[i] + len(chunk)
+            table[j] = self.kv.table[i]
             self.progress[i] += len(chunk)
             if self.progress[i] == len(r.prompt) + len(r.tokens):
                 # last chunk: this row samples its first generated token
                 r.state = RequestState.DECODING
                 self.pos[i] = self.progress[i]
-                sample_index[i] = self.dispatched[i]
+                sample_index[j] = self.dispatched[i]
                 emit.append(Emit(i, r, int(self.dispatched[i])))
                 r._inflight += 1
                 self._inflight_total += 1
@@ -505,9 +522,9 @@ class Scheduler:
                 self.kv.commit_pages(self.kv.owned(i))
         self.prefill_tokens += sum(len(c) for c in chunks.values())
         self.prefill_ticks += 1
-        table = np.where(lens[:, None] > 0, self.kv.table, TRASH_PAGE)
+        self.prefill_rows += rows
         return PrefillChunk(tokens, lens, offsets, scale_base, table,
-                            sample_index, bool(hot), tuple(emit))
+                            sample_index, bool(hot), tuple(emit), row_slots)
 
     def _plan_decode(self, fresh_slots: Tuple[int, ...]) -> Optional[DecodeTick]:
         live = [i for i, r in enumerate(self.active)

@@ -77,6 +77,13 @@ def test_engine_spans_and_counters(tmp_path):
     # (a poll whose plan enqueues nothing carries no steps)
     steps = {s for a in dispatches for s in a.get("steps", "").split("+")}
     assert steps - {""} == {"prefill_step", "decode_step"}
+    # a tick that carries a prefill names its batch's rows: both requests
+    # join in one tick, two prefilling slots in a 2-row batch
+    rows = [a["rows"] for a in dispatches
+            if "prefill_step" in a.get("steps", "")]
+    assert rows == [2]
+    assert all("rows" not in a for a in dispatches
+               if "prefill_step" not in a.get("steps", ""))
     # every span carries the tick it belongs to
     assert all(isinstance(args["tick"], int) for _, args in spans)
 

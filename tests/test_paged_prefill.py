@@ -331,24 +331,28 @@ def test_paged_io_stats_matches_bench_artifact_column():
 def test_engine_prefill_counters_track_chunks():
     """prefill_tokens / prefill_ticks (the TTFT attribution pair): a
     24-token prompt at prefill_slice=8 is exactly 3 chunk ticks and 24
-    prefill tokens; decode ticks leave both untouched."""
+    prefill tokens; decode ticks leave both untouched.  A lone prefilling
+    slot runs 1-row steps, so prefill_rows counts one per tick."""
     cfg = _cfg("dense")
     md = get_model_def(cfg)
     params = init_params(md.specs(cfg), jax.random.PRNGKey(0))
     eng = ServeEngine(md, cfg, params, max_batch=2, max_len=64, page_size=8,
                       mode="sync", prefill_slice=8)
-    assert (eng.prefill_tokens, eng.prefill_ticks) == (0, 0)
+    assert (eng.prefill_tokens, eng.prefill_ticks, eng.prefill_rows) == (
+        0, 0, 0)
     eng.submit(Request(prompt=list(range(50, 74)),
                        sampling=SamplingParams(max_new=4), rid=0))
     eng.run()
     assert eng.prefill_tokens == 24
     assert eng.prefill_ticks == 3
+    assert eng.prefill_rows == 3
     ticks_after = eng.prefill_ticks
     eng.submit(Request(prompt=[1, 2, 3],
                        sampling=SamplingParams(max_new=2), rid=1))
     eng.run()
     assert eng.prefill_tokens == 27  # short prompt: one 3-token chunk
     assert eng.prefill_ticks == ticks_after + 1
+    assert eng.prefill_rows == 4
 
 
 def test_gateway_metrics_exposes_prefill_counters():
@@ -369,5 +373,6 @@ def test_gateway_metrics_exposes_prefill_counters():
     m = gw._metrics()
     assert m["engine"]["prefill_tokens"] == 16
     assert m["engine"]["prefill_ticks"] == 2
+    assert m["engine"]["prefill_rows"] == 2  # one prefilling slot a tick
     assert "spec_proposed" in m["engine"]  # sits next to the spec stats
     assert "preemptions" in m["engine"]
