@@ -1,6 +1,8 @@
-"""Operations, bytes and chip peaks: the yardstick the per-layer metrics
-divide by.  Everything here is computed from the configuration's sizes,
-never read from the program.
+"""Bytes and chip peaks: the yardstick the per-layer metrics divide by.
+Everything here is computed from the configuration's sizes, never read
+from the program.  The matrix weights a token passes through depend on
+the parameter tree, so each weights module counts them
+(``weights/<name>.py`` ``matmul_weights_per_token``).
 
 Peaks are per chip, keyed by ``device_kind`` as JAX reports it.  A device
 that is not in the table is an error, not a default.
@@ -28,24 +30,6 @@ def peaks(device_kind: str) -> dict:
 
 def padded_vocab(model: dict) -> int:
     return -(-model["vocab"] // 128) * 128
-
-
-def matmul_weights_per_token(model: dict) -> int:
-    """Weights of the matrix multiplications one token passes through:
-    q/k/v/o projections, the gated feed-forward and the output head (over
-    the padded vocabulary the program multiplies).  Embedding lookups and
-    attention arithmetic are left out, so every attention backend counts
-    the same."""
-    d, hd = model["d_model"], model["head_dim"]
-    q_dim = model["n_heads"] * hd
-    kv_dim = model["n_kv_heads"] * hd
-    attn = d * q_dim + 2 * d * kv_dim + q_dim * d
-    ffn = 3 * d * model["d_ff"]
-    return model["n_layers"] * (attn + ffn) + d * padded_vocab(model)
-
-
-def matmul_flops_per_token(model: dict) -> int:
-    return 2 * matmul_weights_per_token(model)
 
 
 def cam_kernel_bytes(model: dict, page_size: int, kv_lens) -> int:

@@ -2,7 +2,9 @@
 
 The per-layer readers (``metrics/<name>.py``) take a ``Window``: the
 client records, the engine's counters at the span's two ends, the trace
-summary of the span (traced runs), and the sizes they divide by.
+summary of the span (traced runs), and the sizes they divide by.  A
+counter an engine lacks is left out of both ends, and the readers of it
+read nothing: a parent program's traced runs use this harness too.
 """
 
 from __future__ import annotations
@@ -16,11 +18,12 @@ from harness import counts
 from harness.serve import Record
 from harness.tracefile import Summary
 
-COUNTERS = ("ticks", "prefill_tokens", "prefill_ticks")
+COUNTERS = ("ticks", "prefill_tokens", "prefill_ticks", "prefill_rows")
 
 
 def counters(engine) -> Dict[str, int]:
-    return {k: int(getattr(engine, k)) for k in COUNTERS}
+    return {k: int(getattr(engine, k)) for k in COUNTERS
+            if hasattr(engine, k)}
 
 
 def p95(values) -> Optional[float]:
@@ -38,6 +41,8 @@ class Window:
     model: dict
     engine: dict
     device_kind: str
+    # of the configuration's weights module: its count for ``model``
+    matmul_weights_per_token: int
     trace: Optional[Summary] = None
 
     @property

@@ -1,8 +1,10 @@
 """Plain reference of a decoder LM with CAMformer attention, in float32
 jax.numpy at "highest" matmul precision, with no kernel, cache or batching
 of the program's.  It imports nothing of the program and takes nothing it
-made: the weights are made again from the seed, layer by layer
-(``harness.weights``).
+made: the weights are made again from the seed, layer by layer, by the
+weights module the configuration names (``weights/<name>.py``), which the
+harness hands it, so the program's weights and the reference's come from
+one maker.
 
 The model, per layer (RMSNorm eps 1e-6, RoPE on the two halves of each
 head, gated SiLU feed-forward, optional q/k/v biases):
@@ -43,8 +45,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from harness import weights as W
 
 EPS = 1e-6
 NEG = -1.0e9
@@ -168,20 +168,23 @@ def chunk_ends(prompt_len: int, total: int, t_pad: int, slice_: int):
 class Reference:
     """Readings over sequences of (prompt, served tokens) for one model.
 
-    ``t_pad`` and ``n_seq`` fix the shapes, so each jitted piece compiles
-    once per configuration."""
+    ``weights`` is the configuration's weights module: the reference makes
+    each layer's leaves again with its ``layer`` and the embedding and
+    head with its ``top``.  ``t_pad`` and ``n_seq`` fix the shapes, so
+    each jitted piece compiles once per configuration."""
 
     def __init__(self, model: dict, seed: int, *, t_pad: int, n_seq: int,
-                 prefill_slice: int, control: str | None = None):
+                 prefill_slice: int, weights, control: str | None = None):
         if control is not None and control not in CONTROLS:
             raise ValueError(f"control {control!r} is not one of {CONTROLS}")
         self.control = control
         self.m = model
-        self.key = W.root_key(seed)
+        self.key = weights.root_key(seed)
         self.t_pad, self.n_seq, self.slice = t_pad, n_seq, prefill_slice
         m = model
-        self._layer_w = jax.jit(lambda k, l: W.layer(k, m, l, jnp.float32))
-        self._top_w = jax.jit(lambda k: W.top(k, m, jnp.float32))
+        self._layer_w = jax.jit(
+            lambda k, l: weights.layer(k, m, l, jnp.float32))
+        self._top_w = jax.jit(lambda k: weights.top(k, m, jnp.float32))
         kinds = (None, control)
         self._layer = {c: jax.jit(functools.partial(_layer, m=m, control=c))
                        for c in kinds}

@@ -5,8 +5,9 @@
         --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration and a traffic mix;
-their files are found by name (harness/cell.py).  One process: weights
-made on the device from the seed, ``ServeEngine`` behind the
+their files, and the weights module and reference the configuration
+names, are found by name (harness/cell.py).  One process: weights made
+on the device from the seed by that module, ``ServeEngine`` behind the
 ``EngineRunner`` engine thread, every step shape warmed, the first wave
 of a closed loop prefilled, then a window of ``--seconds`` in which
 clients send the mix and time each token where they receive it.  After
@@ -127,7 +128,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         counts.peaks(dev.device_kind)  # unknown device: error, before work
     clock = CompileClock(jax)
 
-    from harness import check, serve, tracefile, traffic, weights
+    from harness import check, serve, tracefile, traffic
     from harness.window import Window, counters, p95
     from repro.configs.base import ModelConfig
     from repro.models import get_model_def
@@ -136,7 +137,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     cfg = ModelConfig(**model)
     md = get_model_def(cfg)
-    params = weights.program_params(model, seed)
+    W = C.weights(conf["weights"])
+    params = W.program_params(model, seed)
     jax.block_until_ready(params)
     engine = ServeEngine(
         md, cfg, params, seed=seed, max_batch=eng_cfg["max_batch"],
@@ -195,7 +197,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # -- end-to-end statistics, from the client side ----------------------
     full = Window(t0=t0, t1=t1, records=records, before=snap[0],
                   after=snap[-1], model=model, engine=eng_cfg,
-                  device_kind=dev.device_kind)
+                  device_kind=dev.device_kind,
+                  matmul_weights_per_token=W.matmul_weights_per_token(model))
     live = [r for r in records if r.due < t1]
     failed = [r for r in live if r.finish in ("error", "rejected", "timeout")]
     e2e = {}
@@ -241,7 +244,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     ref = ref_mod.Reference(
         model, seed, t_pad=eng_cfg["max_len"], n_seq=corr["sample_requests"],
         prefill_slice=eng_cfg.get("prefill_slice") or eng_cfg["max_len"],
-        control=corr["control"] if control else None)
+        control=corr["control"] if control else None, weights=W)
     t_ref = time.perf_counter()
     got = check.compare(ref, records, seed, corr["sample_requests"],
                         control=control)

@@ -1,11 +1,14 @@
-"""Weights made from ``--seed`` by the benchmark itself, on the device, in
-one jitted call, in the type they are served in (the model's ``dtype``).
+"""Weights of a dense decoder LM (the program's ``dense`` family), made
+from ``--seed`` by the benchmark itself, on the device, in one jitted
+call, in the type they are served in (the model's ``dtype``).
 
-Each leaf of layer ``l`` is ``normal(fold_in(fold_in(key, leaf), l))``
-times its scale, rounded to bf16 (and held in the served type), so one layer's weights can be made
-again on their own (``layer``): that is how the plain reference gets the
-same numbers without taking anything the program holds.  The tree has the
-layout the serving program takes as its parameters.
+Each leaf of layer ``l`` is ``normal(fold_in(fold_in(key, l), leaf))``
+times its scale, rounded to bf16 (and held in the served type), so one
+layer's weights can be made again on their own (``layer``): that is how
+the plain reference gets the same numbers without taking anything the
+program holds.  The tree has the layout the serving program takes as its
+parameters.  Every weights module under ``weights/`` defines the five
+public names of this one (harness/cell.py).
 """
 
 from __future__ import annotations
@@ -27,6 +30,20 @@ BIAS_STD = 0.05
 def root_key(seed: int) -> jax.Array:
     word = np.random.SeedSequence(seed).generate_state(1, np.uint32)[0]
     return jax.random.PRNGKey(int(word))
+
+
+def matmul_weights_per_token(m: dict) -> int:
+    """Weights of the matrix multiplications one token passes through:
+    q/k/v/o projections, the gated feed-forward and the output head (over
+    the padded vocabulary the program multiplies).  Embedding lookups and
+    attention arithmetic are left out, so every attention backend counts
+    the same."""
+    d, hd = m["d_model"], m["head_dim"]
+    q_dim = m["n_heads"] * hd
+    kv_dim = m["n_kv_heads"] * hd
+    attn = d * q_dim + 2 * d * kv_dim + q_dim * d
+    ffn = 3 * d * m["d_ff"]
+    return m["n_layers"] * (attn + ffn) + d * padded_vocab(m)
 
 
 def _shapes(m: dict) -> dict:

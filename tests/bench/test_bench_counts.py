@@ -1,5 +1,5 @@
-"""The yardstick: matmul operations per token, the CAM kernel's bytes,
-and the table of chip peaks."""
+"""The yardstick: matmul weights per token (of the dense weights module),
+the CAM kernel's bytes, and the table of chip peaks."""
 
 import json
 import os
@@ -31,8 +31,7 @@ QWEN = {"n_layers": 8, "d_model": 4096, "n_heads": 32, "n_kv_heads": 4,
 ])
 def test_matmul_flops_per_token(model, weights):
     m = dict(_model(BERT), **model)
-    assert counts.matmul_weights_per_token(m) == weights
-    assert counts.matmul_flops_per_token(m) == 2 * weights
+    assert C.weights("dense_lm").matmul_weights_per_token(m) == weights
 
 
 def test_cam_kernel_bytes_count_live_pages_only():
@@ -51,3 +50,51 @@ def test_peaks_of_a_v5e_and_an_unknown_device():
     assert p["bf16_flops"] == 197e12 and p["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError, match="no peaks"):
         counts.peaks("TPU v9 imaginary")
+
+
+def _counted(before, after, *, weights=0, tokens=0, seconds=1.0):
+    """A window of hand-made engine counters and ``tokens`` delivered."""
+    from types import SimpleNamespace
+
+    from harness.window import Window
+
+    rec = SimpleNamespace(times=[seconds / 2] * tokens)
+    return Window(t0=0.0, t1=seconds, records=[rec], before=before,
+                  after=after, model={},
+                  engine={"max_batch": 64, "prefill_slice": 256},
+                  device_kind="TPU v5 lite",
+                  matmul_weights_per_token=weights)
+
+
+def test_prefill_pad_share_divides_by_the_rows_the_step_computes():
+    read = C.reader("prefill_pad_share.decode")
+    # 30 one-row prefill steps of 256 positions carried 4900 prompt tokens
+    w = _counted({"prefill_tokens": 100, "prefill_ticks": 5,
+                  "prefill_rows": 10},
+                 {"prefill_tokens": 5000, "prefill_ticks": 35,
+                  "prefill_rows": 40})
+    assert read(w) == pytest.approx(1 - 4900 / (30 * 256))
+    # no prefill in the window; an engine without the counter
+    assert read(_counted({"prefill_tokens": 0, "prefill_rows": 4},
+                         {"prefill_tokens": 0, "prefill_rows": 4})) is None
+    assert read(_counted({"prefill_tokens": 0, "prefill_ticks": 0},
+                         {"prefill_tokens": 90, "prefill_ticks": 1})) is None
+
+
+def test_step_mfu_counts_with_the_weights_modules_count():
+    w = _counted({"prefill_tokens": 0}, {"prefill_tokens": 500},
+                 weights=1_000_000, tokens=1500, seconds=2.0)
+    assert C.reader("step_mfu.decode")(w) == pytest.approx(
+        100 * 2000 * 2 * 1_000_000 / 2.0 / 197e12)
+
+
+def test_counters_skip_a_counter_the_engine_lacks():
+    from types import SimpleNamespace
+
+    from harness.window import counters
+
+    engine = SimpleNamespace(ticks=3, prefill_tokens=5, prefill_ticks=1)
+    assert counters(engine) == {"ticks": 3, "prefill_tokens": 5,
+                                "prefill_ticks": 1}
+    engine.prefill_rows = 2
+    assert counters(engine)["prefill_rows"] == 2

@@ -13,6 +13,7 @@ import pytest
 
 from harness import cell as C
 from harness import serve, traffic
+from test_bench_weights import MOE, assert_the_programs_tree
 
 REPO = C.REPO
 RUN = os.path.join(C.HERE, "run.py")
@@ -27,6 +28,7 @@ def test_every_cell_resolves_to_its_files():
         assert c.traffic["loop"] == "closed"
         assert c.config["reference"]
         C.reference(c.config["reference"]).Reference  # noqa: B018
+        assert callable(C.weights(c.config["weights"]).program_params)
         names = {m["name"] for m in c.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert c.per_layer
@@ -41,14 +43,25 @@ def test_every_cell_resolves_to_its_files():
             assert key in body, (conf["name"], key)
 
 
-def test_a_cell_added_as_new_files_loads(tmp_path, monkeypatch):
-    """A new configuration, mix and per-layer metric are new files plus
-    new entries in BENCHMARK.json; no existing file changes."""
+@pytest.mark.parametrize("weights", ["moe_lm", None, "no_such_module"])
+def test_a_cell_added_as_new_files_loads(weights, tmp_path, monkeypatch):
+    """A new configuration, its weights module, mix and per-layer metric
+    are new files plus new entries in BENCHMARK.json; no existing file
+    changes.  The configuration names a weights module that makes the
+    program's expert tree; one that names no module, or one that is not
+    there, is an error at resolve."""
     here = tmp_path / "chip"
     shutil.copytree(C.HERE, here, ignore=shutil.ignore_patterns("_runs"))
     bench = C.load_benchmark()
-    shutil.copy(here / "configs" / "camformer-bert-f32.json",
-                here / "configs" / "new-model.json")
+    shutil.copy(os.path.join(os.path.dirname(__file__), "moe_lm.py"),
+                here / "weights" / "moe_lm.py")
+    conf = json.loads((here / "configs" / "camformer-bert-f32.json")
+                      .read_text())
+    conf.update(name="new-model", model=MOE)
+    conf.pop("weights")
+    if weights:
+        conf["weights"] = weights
+    (here / "configs" / "new-model.json").write_text(json.dumps(conf))
     (here / "traffic" / "new-mix.json").write_text(json.dumps(
         {"loop": "closed", "clients": 3,
          "prompt": {"dist": "uniform", "lo": 8, "hi": 9},
@@ -68,12 +81,19 @@ def test_a_cell_added_as_new_files_loads(tmp_path, monkeypatch):
                                "moves": "itl_p95_ms",
                                "workloads": ["new.cell"]})
     monkeypatch.setattr(C, "HERE", str(here))
+    if weights != "moe_lm":
+        with pytest.raises(ValueError, match="names (no|the) weights module"):
+            C.resolve(bench, "new.cell")
+        return
     c = C.resolve(bench, "new.cell")
     assert c.traffic["clients"] == 3
     assert [m["name"] for m in c.per_layer] == ["new_metric.decode"]
     assert C.reader("new_metric.decode")(None) == 42.0
     assert {m["name"] for m in c.end_to_end} == {
         "tokens_per_s", "itl_p95_ms", "setup_s"}
+    W = C.weights(c.config["weights"])
+    assert W.__file__ == str(here / "weights" / "moe_lm.py")
+    assert_the_programs_tree(W, c.config["model"])
 
 
 MIX = {"loop": "closed", "clients": 16, "steady_first_wave": True,
@@ -207,12 +227,14 @@ def test_importing_the_harness_loads_no_libtpu():
         f"sys.path.insert(0, {os.path.join(REPO, 'src')!r})\n"
         "import run, control\n"
         "from harness import cell, check, counts, serve, tracefile, "
-        "traffic, weights, window\n"
+        "traffic, window\n"
         "b = cell.load_benchmark()\n"
         "for m in b['per_layer']: cell.reader(m['name'])\n"
+        "import json\n"
         "for c in b['configs']:\n"
-        "    import json; cell.reference(json.load(open("
-        f"{REPO!r} + '/' + c['file']))['reference'])\n"
+        f"    conf = json.load(open({REPO!r} + '/' + c['file']))\n"
+        "    cell.reference(conf['reference'])\n"
+        "    cell.weights(conf['weights'])\n"
         "maps = open('/proc/self/maps').read()\n"
         "print('libtpu' in maps)\n")
     env = {k: v for k, v in os.environ.items()

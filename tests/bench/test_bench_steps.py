@@ -136,7 +136,8 @@ def test_the_idle_gap_under_a_readback_is_named_by_its_span(runs):
 def _window(trace, ticks):
     return Window(t0=0.0, t1=1.0, records=[], before={"ticks": 0},
                   after={"ticks": ticks}, model={}, engine={},
-                  device_kind="TPU v5 lite", trace=trace)
+                  device_kind="TPU v5 lite", matmul_weights_per_token=0,
+                  trace=trace)
 
 
 def test_the_step_and_host_readers(runs):
